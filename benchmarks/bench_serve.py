@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import tempfile
 import threading
@@ -307,16 +306,8 @@ def main() -> dict:
 
     with tempfile.TemporaryDirectory() as tmp:
         out = os.path.join(tmp, "serve.json")
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICES}"
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(os.path.dirname(__file__), "..", "src"),
-             os.path.dirname(os.path.dirname(__file__)),
-             env.get("PYTHONPATH", "")])
-        subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_serve", "--child", out],
-            env=env, check=True,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        common.run_child_module("benchmarks.bench_serve", ["--child", out],
+                                DEVICES)
         with open(out) as f:
             res = json.load(f)
 

@@ -79,13 +79,16 @@ def free_port() -> int:
 def spawn_child_module(module: str, args, num_devices: int):
     """Start ``python -m module *args`` detached, with ``num_devices``
     virtual CPU devices (jax locks the device count at first init, so
-    multi-device configurations cannot run in the parent).  Combine with
+    multi-device configurations cannot run in the parent).  The child is
+    pinned to the CPU: it exists only for virtual devices, and must never
+    compete with the parent for an accelerator.  Combine with
     :func:`wait_children`; multi-process fabrics spawn one child per
     process against a :func:`free_port` coordinator."""
     import subprocess
     import sys
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={num_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          os.path.dirname(os.path.dirname(__file__)),

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Sequence
 
 import jax
@@ -27,11 +28,15 @@ import jax.numpy as jnp
 
 
 from repro.kernels import tune as KT
-from repro.quant import (INT32_CODE_MIN, INT32_CODE_MAX,
-                         validate_eps_positive as _validate_eps_positive)
+from repro import f32, quant
+from repro.quant import validate_eps_positive as _validate_eps_positive
 
 DEFAULT_VARIANCE_FRACTION_2D = 0.99
 DEFAULT_VARIANCE_FRACTION_3D = 0.90
+
+# The Gram matrices are f32 products.  On the TPU a default-precision f32
+# matmul rounds its operands to bf16, which moves the truncation count.
+_F32 = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -97,8 +102,8 @@ def hosvd_trunc_batch(
             from repro.kernels.gram import ops as gram_ops
             g = gram_ops.gram_batched(u, transpose=p >= q, tune=tune)
         else:
-            g = (jnp.einsum("kai,kaj->kij", u, u) if p >= q
-                 else jnp.einsum("kia,kja->kij", u, u))
+            g = (jnp.einsum("kai,kaj->kij", u, u, precision=_F32) if p >= q
+                 else jnp.einsum("kia,kja->kij", u, u, precision=_F32))
         ev = jnp.maximum(jnp.linalg.eigvalsh(g), 0.0)[:, ::-1]   # descending
         total = jnp.sum(ev, axis=1, keepdims=True)
         cum = jnp.cumsum(ev, axis=1)
@@ -134,7 +139,7 @@ def hosvd_trunc(
 def _entropy_from_counts(counts: jnp.ndarray) -> jnp.ndarray:
     n = jnp.maximum(jnp.sum(counts), 1)
     p = counts / n
-    return -jnp.sum(jnp.where(p > 0, p * jnp.log2(jnp.maximum(p, 1e-30)), 0.0))
+    return -jnp.sum(jnp.where(p > 0, p * f32.log2(jnp.maximum(p, 1e-30)), 0.0))
 
 
 def quantized_codes(x: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -145,8 +150,7 @@ def quantized_codes(x: jnp.ndarray, eps: float) -> jnp.ndarray:
     (value, eps) pairs saturate instead of silently wrapping.
     """
     _validate_eps_positive(eps)
-    scaled = jnp.floor(x / eps)
-    return jnp.clip(scaled, INT32_CODE_MIN, INT32_CODE_MAX).astype(jnp.int32)
+    return quant.codes(x, eps)
 
 
 def quantized_entropy(
@@ -200,6 +204,16 @@ class PredictorConfig:
     tune: KT.TuneConfig = KT.TuneConfig()
 
 
+def _qent_feature(qe):
+    """log(q-ent), guarded: q-ent can be 0 (all values in one bin)."""
+    return f32.log(jnp.maximum(qe, 1e-3))
+
+
+def _ratio_feature(sv, sigma):
+    """log(truncation level / sigma), guarded: sigma can be 0."""
+    return f32.log(jnp.maximum(sv, 1e-6) / jnp.maximum(sigma, 1e-12))
+
+
 def features_2d(x: jnp.ndarray, eps: float, cfg: PredictorConfig = PredictorConfig()) -> jnp.ndarray:
     """The paper's predictor vector for one 2-D slice at error bound ``eps``:
     ``[log(q_ent), log(svd_trunc / sigma)]`` (both standardized downstream).
@@ -209,9 +223,8 @@ def features_2d(x: jnp.ndarray, eps: float, cfg: PredictorConfig = PredictorConf
                    tune=cfg.tune)
     qe = quantized_entropy(x, eps, cfg.qent_bins, use_kernel=cfg.use_kernels,
                            tune=cfg.tune)
-    # Guard logs: q-ent can be 0 (all values in one bin) and sigma can be 0.
-    log_qe = jnp.log(jnp.maximum(qe, 1e-3))
-    log_ratio = jnp.log(jnp.maximum(sv, 1e-6) / jnp.maximum(sigma, 1e-12))
+    log_qe = _qent_feature(qe)
+    log_ratio = _ratio_feature(sv, sigma)
     return jnp.stack([log_qe, log_ratio])
 
 
@@ -221,8 +234,8 @@ def features_3d(x: jnp.ndarray, eps: float, cfg: PredictorConfig = PredictorConf
                      tune=cfg.tune)
     qe = quantized_entropy(x, eps, cfg.qent_bins, use_kernel=cfg.use_kernels,
                            tune=cfg.tune)
-    log_qe = jnp.log(jnp.maximum(qe, 1e-3))
-    log_ratio = jnp.log(jnp.maximum(sv, 1e-6) / jnp.maximum(sigma, 1e-12))
+    log_qe = _qent_feature(qe)
+    log_ratio = _ratio_feature(sv, sigma)
     return jnp.stack([log_qe, log_ratio])
 
 
@@ -260,8 +273,8 @@ def svd_trunc_batch(
         from repro.kernels.gram import ops as gram_ops
         g = gram_ops.gram_batched(x, transpose=m >= n, tune=tune)
     else:
-        g = (jnp.einsum("kai,kaj->kij", x, x) if m >= n
-             else jnp.einsum("kia,kja->kij", x, x))
+        g = (jnp.einsum("kai,kaj->kij", x, x, precision=_F32) if m >= n
+             else jnp.einsum("kia,kja->kij", x, x, precision=_F32))
     ev = jnp.maximum(jnp.linalg.eigvalsh(g), 0.0)[:, ::-1]   # descending
     p = ev.shape[1]
     total = jnp.sum(ev, axis=1, keepdims=True)
@@ -322,18 +335,17 @@ def quantized_entropy_sweep(
         # lax.map over ebs keeps the peak working set at (k, n) -- the
         # same order as one step of the looped baseline -- instead of
         # materializing (k, e, n) temporaries for the whole sweep.
-        codes = jnp.clip(jnp.floor(xs / eps),         # saturate, don't wrap
-                         INT32_CODE_MIN, INT32_CODE_MAX).astype(jnp.int32)
-        start = jnp.concatenate(                      # run starts, (k, n)
-            [ones, codes[:, 1:] != codes[:, :-1]], axis=1)
+        codes = quant.codes(xs, eps)
+        new = codes[:, 1:] != codes[:, :-1]
+        start = jnp.concatenate([ones, new], axis=1)  # run starts, (k, n)
+        end = jnp.concatenate([new, ones], axis=1)    # run ends
         run_start = jax.lax.cummax(jnp.where(start, iota, 0), axis=1)
-        # H = log2(n) - (1/n) sum_runs L*log2(L).  Telescoping over the
-        # rank j = 1..L inside each run, L*log2(L) = sum_j g(j) with
-        # g(j) = j*log2(j) - (j-1)*log2(j-1), so one forward cummax (the
-        # rank) replaces any backward pass or per-run reduction.
+        # H = log2(n) - (1/n) sum_runs L*log2(L).  A run's last element
+        # has rank L inside the run, so one forward cummax (the rank)
+        # replaces any backward pass or per-run reduction.
         j = (iota - run_start + 1).astype(jnp.float32)
-        g = j * jnp.log2(j) - (j - 1) * jnp.log2(jnp.maximum(j - 1, 1))
-        return jnp.log2(float(n)) - jnp.sum(g, axis=1) / n
+        s = jnp.sum(jnp.where(end, j * f32.log2(j), 0.0), axis=1)
+        return jnp.float32(math.log2(n)) - s / n
 
     return jax.lax.map(one_eps, epss).T               # (e, k) -> (k, e)
 
@@ -381,10 +393,10 @@ def _features_sweep_impl(slices, epss, *, vf, bins, use_kernels, tune=None,
             sv = svd_trunc_batch(x, vf, use_kernel=use_kernels, tune=tune)
         else:
             sv = hosvd_trunc_batch(x, vf, use_kernel=use_kernels, tune=tune)
-        log_ratio = jnp.log(jnp.maximum(sv, 1e-6) / jnp.maximum(sigma, 1e-12))
+        log_ratio = _ratio_feature(sv, sigma)
         qe = quantized_entropy_sweep(x, epss, bins, use_kernel=use_kernels,
                                      tune=tune)
-        log_qe = jnp.log(jnp.maximum(qe, 1e-3))             # (k, e)
+        log_qe = _qent_feature(qe)                          # (k, e)
         outs.append(jnp.stack(
             [log_qe, jnp.broadcast_to(log_ratio[:, None], log_qe.shape)],
             axis=-1))
@@ -546,8 +558,7 @@ class SliceCache:
                 self._x,
                 vf=variance_fraction_for(self._cfg, self._x.ndim + 1),
                 use_kernels=self._cfg.use_kernels, tune=self._cfg.tune)
-            self._log_ratio = jnp.log(
-                jnp.maximum(sv, 1e-6) / jnp.maximum(sigma, 1e-12))
+            self._log_ratio = _ratio_feature(sv, sigma)
         return self._log_ratio
 
     def prefetch(self, epss) -> jnp.ndarray:
@@ -585,7 +596,7 @@ class SliceCache:
                 bins=self._cfg.qent_bins,
                 use_kernels=self._cfg.use_kernels, tune=self._cfg.tune)[0]
             self._memo[key] = jnp.stack(
-                [jnp.log(jnp.maximum(qe, 1e-3)), self._ratio()])
+                [_qent_feature(qe), self._ratio()])
         return self._memo[key]
 
 

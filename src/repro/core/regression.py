@@ -22,6 +22,13 @@ import jax
 import jax.numpy as jnp
 
 
+def _mm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """f32 matrix product.  At default precision the TPU rounds f32
+    operands to bf16, which turns the cubic spline designs' normal
+    equations into noise."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 class Standardizer(NamedTuple):
     mean: jnp.ndarray
     std: jnp.ndarray
@@ -55,16 +62,16 @@ class LinearCRModel(NamedTuple):
         std = Standardizer.fit(features)
         x = _linear_design(std(features))
         y = jnp.log(cr)
-        xtx = x.T @ x + ridge * jnp.eye(x.shape[1])
-        coef = jnp.linalg.solve(xtx, x.T @ y)
+        xtx = _mm(x.T, x) + ridge * jnp.eye(x.shape[1])
+        coef = jnp.linalg.solve(xtx, _mm(x.T, y))
         return LinearCRModel(std, coef)
 
     def predict(self, features: jnp.ndarray) -> jnp.ndarray:
         x = _linear_design(self.std(features))
-        return jnp.exp(x @ self.coef)
+        return jnp.exp(_mm(x, self.coef))
 
     def predict_log(self, features: jnp.ndarray) -> jnp.ndarray:
-        return _linear_design(self.std(features)) @ self.coef
+        return _mm(_linear_design(self.std(features)), self.coef)
 
 
 # ---------------------------------------------------------------------------
@@ -134,16 +141,16 @@ class SplineCRModel(NamedTuple):
         y = jnp.log(cr)
         # Penalized LS; don't penalize intercept.
         pen = ridge * jnp.eye(x.shape[1]).at[0, 0].set(0.0)
-        coef = jnp.linalg.solve(x.T @ x + pen, x.T @ y)
+        coef = jnp.linalg.solve(_mm(x.T, x) + pen, _mm(x.T, y))
         return SplineCRModel(std, knots1, knots2, coef)
 
     def predict(self, features: jnp.ndarray) -> jnp.ndarray:
         x = _spline_design(self.std(features), self.knots1, self.knots2)
-        return jnp.exp(x @ self.coef)
+        return jnp.exp(_mm(x, self.coef))
 
     def predict_log(self, features: jnp.ndarray) -> jnp.ndarray:
         x = _spline_design(self.std(features), self.knots1, self.knots2)
-        return x @ self.coef
+        return _mm(x, self.coef)
 
 
 # ---------------------------------------------------------------------------
@@ -162,15 +169,15 @@ def lasso_fit(x: jnp.ndarray, y: jnp.ndarray, lam: jnp.ndarray, num_iters: int =
     Returns coefficient vector (p,).
     """
     n = x.shape[0]
-    xtx = x.T @ x / n
-    xty = x.T @ y / n
+    xtx = _mm(x.T, x) / n
+    xty = _mm(x.T, y) / n
     lipschitz = jnp.linalg.eigvalsh(xtx)[-1] + 1e-8
     step = 1.0 / lipschitz
     mask = jnp.ones(x.shape[1]).at[0].set(0.0)  # don't penalize intercept
 
     def body(_, carry):
         b, v, t = carry
-        grad = xtx @ v - xty
+        grad = _mm(xtx, v) - xty
         b_new = _soft_threshold(v - step * grad, step * lam * 1.0) * mask + \
             (v - step * grad) * (1 - mask)
         t_new = (1 + jnp.sqrt(1 + 4 * t * t)) / 2
@@ -212,7 +219,7 @@ def lasso_importance(
             # weighted LS via FISTA on weighted matrices
             xw = x * w[:, None]
             b = lasso_fit(xw, yz * w, lam)
-            resid = (x @ b - yz) * test_mask
+            resid = (_mm(x, b) - yz) * test_mask
             errs.append(jnp.sum(resid**2) / jnp.maximum(jnp.sum(test_mask), 1))
         return jnp.mean(jnp.stack(errs))
 

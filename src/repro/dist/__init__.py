@@ -1,14 +1,9 @@
 """Distribution layer: logical-axis sharding, sharded featurization
 sweeps, compressed collectives, elastic fault handling.
 
-Importing this package installs compatibility polyfills for older jax
-releases (``jax.shard_map`` as a thin adapter over
-``jax.experimental.shard_map``) so the call sites can use the modern
-spelling unconditionally.
-
 ``repro.dist.sweep`` is the multi-device sweep layer: activate a mesh via
 ``sharding.use_mesh`` and every ``features_sweep`` shards its slice axis
 across the mesh's "data" axis (padding non-divisible slice counts,
 gathering -- or optionally keeping sharded -- the (k, e, 2) result).
 """
-from repro.dist import sharding  # noqa: F401  (installs jax compat shims)
+from repro.dist import sharding  # noqa: F401

@@ -6,10 +6,6 @@ helpers here resolve those rules against the active mesh, with a
 per-dimension divisibility fallback to replication (a 25-head tensor on a
 4-way model axis silently replicates instead of erroring).
 
-Also hosts the jax version-compat shims for APIs the call sites use
-unconditionally (``shard_map`` with ``axis_names``, ``pvary``,
-abstract-mesh lookup).
-
 Distributed featurization sweeps
 --------------------------------
 The sweep engine (``repro.core.predictors.features_sweep``) shards its
@@ -130,112 +126,29 @@ def named_sharding(shape: Sequence[int], logical_axes, mesh: Optional[Mesh] = No
 
 
 def in_manual_context() -> bool:
-    """True while tracing inside a shard_map body (any jax version).
-
-    The old-jax adapter marks its bodies via the ``manual_depth`` flag;
-    native ``jax.shard_map`` is detected through the abstract mesh's
-    Manual axis types (same probe ``pvary_manual`` uses).
-    """
-    if getattr(_STATE, "manual_depth", 0) > 0:
-        return True
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        return any(str(am._axis_types_dict.get(n, "")) == "Manual"
-                   for n in am.axis_names)
-    except Exception:
-        return False
+    """True while tracing inside a ``jax.shard_map`` body."""
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def shard(x, *logical_axes):
     """Constrain ``x`` to the sharding implied by its logical axes; no-op
-    when no mesh is active (single-host tests, CPU smoke runs) or while
-    tracing inside a compat full-manual shard_map body (old jax cannot
-    express auto-axis constraints there)."""
+    when no mesh is active (single-host tests, CPU smoke runs).  Inside a
+    ``jax.shard_map`` body the constraint names the tracing-time abstract
+    mesh, whose manual axes carry the ``Manual`` type ``x`` varies over."""
     mesh = current_mesh()
-    if mesh is None or getattr(_STATE, "manual_depth", 0) > 0:
+    if mesh is None:
         return x
     spec = spec_for(x.shape, logical_axes, mesh)
+    if in_manual_context():
+        mesh = jax.sharding.get_abstract_mesh()
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-# ---------------------------------------------------------------------------
-# jax version-compat shims
-# ---------------------------------------------------------------------------
-
 def pvary_manual(tree):
-    """Mark ``tree`` as varying over the currently-manual shard_map axes.
-
-    On jax releases with the vma type system this applies ``jax.lax.pvary``
-    so scan carries type-check; older releases have no pvary (and our
-    shard_map adapter disables replication checking), so identity is
-    exactly equivalent there.
-    """
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is None:
-        return tree
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        manual = tuple(n for n in am.axis_names
-                       if str(am._axis_types_dict.get(n, "")) == "Manual")
-    except Exception:
-        manual = ()
+    """Mark ``tree`` as varying over the currently-manual shard_map axes,
+    so scan carries created inside a shard_map body type-check."""
+    manual = tuple(jax.sharding.get_abstract_mesh().manual_axes)
     if not manual:
         return tree
-    return jax.tree.map(lambda a: pvary(a, manual), tree)
-
-
-# The native jax.shard_map at import time (None on old jax, where the
-# polyfill below installs an adapter -- keep the original to avoid
-# dispatching the adapter to itself).
-_NATIVE_SHARD_MAP = getattr(jax, "shard_map", None)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=frozenset()):
-    """Modern ``jax.shard_map`` spelling (manual over ``axis_names``, auto
-    over the rest) adapted to ``jax.experimental.shard_map`` on old jax.
-
-    Old-jax note: the partial-auto mode (``auto=``) crashes the 0.4.x SPMD
-    partitioner, so the adapter runs the body FULL-manual over every mesh
-    axis with replication checking off.  Axes the caller wanted auto are
-    simply unsharded inside the body (redundant compute, identical
-    values), and ``shard()`` constraints inside the body become no-ops via
-    the manual-depth flag.
-    """
-    if _NATIVE_SHARD_MAP is not None:
-        return _NATIVE_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs,
-                                 axis_names=frozenset(axis_names))
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def body(*args):
-        _STATE.manual_depth = getattr(_STATE, "manual_depth", 0) + 1
-        try:
-            return f(*args)
-        finally:
-            _STATE.manual_depth -= 1
-
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def abstract_mesh_or(mesh: Optional[Mesh] = None):
-    """The tracing-time abstract mesh when available, else the concrete
-    mesh (old jax builds NamedShardings from concrete meshes only)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        am = get()
-        if am is not None and getattr(am, "axis_names", ()):
-            return am
-    return mesh if mesh is not None else current_mesh()
-
-
-def _install_compat() -> None:
-    if not hasattr(jax, "shard_map"):
-        def _jax_shard_map(f, *, mesh, in_specs, out_specs,
-                           axis_names=frozenset(), **kw):
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names)
-        jax.shard_map = _jax_shard_map
-
-
-_install_compat()
+    return jax.tree.map(lambda a: jax.lax.pcast(a, manual, to="varying"),
+                        tree)

@@ -337,11 +337,13 @@ def _sharded_sweep_fn(mesh: Mesh, axes: tuple, rank: int, vf: float,
             local_slices, epss, vf=vf, bins=bins, use_kernels=use_kernels,
             tune=tune, mode=mode)
 
-    f = S.shard_map(
+    # check_vma=False: the body is per-device local compute with no
+    # collectives, and its Pallas kernels declare no varying-axes types
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(part, *([None] * (rank - 1))), P(None)),
         out_specs=P(part, None, None),
-        axis_names=frozenset(axes))
+        axis_names=frozenset(axes), check_vma=False)
     return jax.jit(f, donate_argnums=(0,) if donate else ())
 
 

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import backend
+
 
 DEFAULT_BN = 128   # output tile edge (MXU-aligned)
 DEFAULT_BK = 128   # contraction tile
@@ -29,6 +31,7 @@ def _gram_kernel(x1_ref, x2_ref, o_ref):
     b = x2_ref[...]            # (bk, bn) tile of X[:, j-block]
     o_ref[...] += jax.lax.dot_general(
         a, b, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,   # f32 passes, not bf16
         preferred_element_type=jnp.float32,
     )
 
@@ -48,7 +51,8 @@ def gram_xtx(x: jnp.ndarray, bn: int = DEFAULT_BN, bk: int = DEFAULT_BK) -> jnp.
         ],
         out_specs=pl.BlockSpec((bn, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
-        interpret=_interpret_default(),
+        interpret=backend.interpret(),
+        name="gram",
     )(x, x)
 
 
@@ -68,6 +72,7 @@ def _gram_batched_kernel(x1_ref, x2_ref, o_ref):
     b = x2_ref[0]              # (bk, bn) tile of X[s, :, j-block]
     o_ref[0] += jax.lax.dot_general(
         a, b, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,   # f32 passes, not bf16
         preferred_element_type=jnp.float32,
     )
 
@@ -88,10 +93,6 @@ def gram_xtx_batched(x: jnp.ndarray, bn: int = DEFAULT_BN,
         ],
         out_specs=pl.BlockSpec((1, bn, bn), lambda s, i, j, t: (s, i, j)),
         out_shape=jax.ShapeDtypeStruct((k, n, n), jnp.float32),
-        interpret=_interpret_default(),
+        interpret=backend.interpret(),
+        name="gram_batched",
     )(x, x)
-
-
-def _interpret_default() -> bool:
-    """TPU lowering on TPU backends, interpreter elsewhere (CPU CI)."""
-    return jax.default_backend() != "tpu"

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend
+
 DEFAULT_BM = 256
 DEFAULT_BN = 256
 
@@ -64,5 +66,5 @@ def lorenzo2d(
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec, spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=jax.default_backend() != "tpu",
+        interpret=backend.interpret(),
     )(eps_arr, a, b, c, d)

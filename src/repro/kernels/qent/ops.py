@@ -8,6 +8,7 @@ import jax.numpy as jnp
 from repro.kernels import tune as _tune
 from repro.kernels.qent import qent as _k
 from repro.kernels.qent import ref as _ref
+from repro import quant
 from repro.quant import validate_eps_positive as _check_eps
 
 
@@ -23,7 +24,9 @@ def quantized_entropy_sweep(
 
     ``x``: (k, ...) stack (trailing dims flattened per slice);
     ``epss``: (e,).  Returns (k, e) bits/symbol from one fused kernel
-    launch that reads each input tile once.  Per-slice padding reuses the
+    launch that reads each input tile once (eb vectors wider than
+    ``qent.max_eps_per_launch(num_bins)`` take one launch per block of
+    that many ebs, to bound the resident histogram).  Per-slice padding reuses the
     slice's own first element (so the pad lands in an existing bin) and
     its count is subtracted from that bin per eps afterwards.
 
@@ -44,12 +47,13 @@ def quantized_entropy_sweep(
             [flat, jnp.broadcast_to(flat[:, :1], (k, pad))], axis=1)
     else:
         flat_p = flat
-    hist = _k.qent_histogram_sweep(flat_p, epss, tile=tile,
-                                   bins=num_bins)  # (k, e, B)
+    step = _k.max_eps_per_launch(num_bins)
+    hist = jnp.concatenate([                          # (k, e, B)
+        _k.qent_histogram_sweep(flat_p, epss[i:i + step], tile=tile,
+                                bins=num_bins)
+        for i in range(0, e, step)], axis=1)
     if pad:
-        first_code = jnp.clip(               # same saturation as the kernel
-            jnp.floor(flat[:, :1] / epss[None, :]),
-            _k.INT32_CODE_MIN, _k.INT32_CODE_MAX).astype(jnp.int32)
+        first_code = quant.codes(flat[:, :1], epss[None, :])
         idx = first_code % num_bins        # jnp floored-mod: already in [0, B)
         hist = hist.at[jnp.arange(k)[:, None], jnp.arange(e)[None, :], idx
                        ].add(-pad)

@@ -2,9 +2,11 @@
 import jax
 import jax.numpy as jnp
 
+from repro import f32, quant
+
 
 def qent_histogram(x: jnp.ndarray, eps, bins: int = 4096) -> jnp.ndarray:
-    codes = jnp.floor(x.reshape(-1) / eps).astype(jnp.int32)
+    codes = quant.codes(x.reshape(-1), eps)
     idx = jax.lax.rem(codes, bins)
     idx = jnp.where(idx < 0, idx + bins, idx)
     return jnp.zeros((bins,), jnp.int32).at[idx].add(1)
@@ -13,7 +15,7 @@ def qent_histogram(x: jnp.ndarray, eps, bins: int = 4096) -> jnp.ndarray:
 def entropy_bits(hist: jnp.ndarray) -> jnp.ndarray:
     n = jnp.maximum(jnp.sum(hist), 1)
     p = hist / n
-    return -jnp.sum(jnp.where(p > 0, p * jnp.log2(jnp.maximum(p, 1e-30)), 0.0))
+    return -jnp.sum(jnp.where(p > 0, p * f32.log2(jnp.maximum(p, 1e-30)), 0.0))
 
 
 def quantized_entropy(x: jnp.ndarray, eps, bins: int = 4096) -> jnp.ndarray:
@@ -24,7 +26,7 @@ def entropy_bits_rows(hist: jnp.ndarray) -> jnp.ndarray:
     """Entropy along the last (bins) axis of a histogram stack."""
     n = jnp.maximum(jnp.sum(hist, axis=-1, keepdims=True), 1)
     p = hist / n
-    return -jnp.sum(jnp.where(p > 0, p * jnp.log2(jnp.maximum(p, 1e-30)), 0.0),
+    return -jnp.sum(jnp.where(p > 0, p * f32.log2(jnp.maximum(p, 1e-30)), 0.0),
                     axis=-1)
 
 

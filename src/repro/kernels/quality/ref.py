@@ -23,7 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.quant import INT32_CODE_MAX, INT32_CODE_MIN
+from repro import f32, quant
 
 # The tile is part of the numerical spec, not a tuning knob: SSE partial
 # sums depend on the accumulation boundaries, so every route (reference,
@@ -50,9 +50,7 @@ def qdq_error_sq(x, eps):
     (``repro.quant``): codes are ``floor(x / eps)`` clipped to the int32
     range, dequantized as ``code * eps``.
     """
-    codes = jnp.clip(jnp.floor(x / eps), INT32_CODE_MIN,
-                     INT32_CODE_MAX).astype(jnp.int32)
-    err = x - codes.astype(jnp.float32) * eps
+    err = x - quant.codes(x, eps).astype(jnp.float32) * eps
     return err * err
 
 
@@ -74,12 +72,7 @@ def tile_sse(err2):
 
 
 def tile_sse_all_eps(xt, epss, n_eps):
-    """One (..., 8, c) tile -> (..., n_eps) SSE, one eps at a time.
-
-    Shared verbatim by the reference loop and the Pallas kernel body
-    (``epss`` may be a traced array or an SMEM ref -- both index the
-    same way), so the two routes run structurally identical ops.
-    """
+    """One (..., 8, c) tile -> (..., n_eps) SSE, one eps at a time."""
     return jnp.stack([tile_sse(qdq_error_sq(xt, epss[ei]))
                       for ei in range(n_eps)], axis=-1)
 
@@ -91,19 +84,22 @@ def sse_sweep(xb, epss, tile):
     reshaped (k, n/8, 8) and swapped to (k, 8, n/8), so element i sits
     at sublane i % 8, column i // 8, and tile t covers the contiguous
     elements [t*tile, (t+1)*tile).  Tiles accumulate sequentially --
-    the same order as the kernel's (k, T) grid with T fastest.
+    the same order as the kernel's (k, T) grid with T fastest -- in a
+    ``lax.scan``, so the program stays one tile long however many tiles
+    a slice or volume has.
     """
     n_eps = int(epss.shape[0])
     c = tile // 8
-    steps = xb.shape[2] // c
-    acc = jnp.zeros(xb.shape[:1] + (n_eps,), jnp.float32)
-    for t in range(steps):
-        acc = acc + tile_sse_all_eps(xb[:, :, t * c:(t + 1) * c], epss, n_eps)
+    k, _, w = xb.shape
+    tiles = jnp.moveaxis(xb.reshape(k, 8, w // c, c), 2, 0)   # (T, k, 8, c)
+
+    def step(acc, xt):
+        return acc + tile_sse_all_eps(xt, epss, n_eps), None
+
+    acc, _ = jax.lax.scan(step, jnp.zeros((k, n_eps), jnp.float32), tiles)
     return acc
 
 
-# 1/ln(2) and log10(2) as f32 constants for the deterministic log10.
-_INV_LN2 = 1.4426950408889634
 _LOG10_2 = 0.30102999566398120
 
 
@@ -113,34 +109,17 @@ def det_log10(x):
     Library ``log`` implementations are NOT batch-shape-invariant on
     CPU -- the SIMD main loop and the scalar remainder round differently,
     so the same element changes bits when its array length changes
-    (exactly what sharding does).  This one uses only bitcasts, +, *, /
-    (each IEEE correctly rounded per element), so its bits never move
-    with the batch shape.
-
-    Split x = m * 2**e with m in [1, 2) via the f32 bit layout
-    (subnormals pre-scaled by 2**64), then log2(m) from the atanh
-    series in t = (m-1)/(m+1) (|t| <= 1/3: the t**15 tail is < 1e-8,
-    below f32 resolution).  x <= 0 maps to -1e4, which the PSNR clip
-    floors out exactly like the -inf a true log would give.  XLA CPU
-    runs with denormals-are-zero, so subnormal inputs take the same
-    -1e4 branch -- a subnormal data range degrades to the clip caps,
-    deterministically, on every route.
+    (exactly what sharding does) -- and the TPU's is off by ~1e-4
+    relative.  ``f32.log2`` uses only bitcasts, +, * and a correctly
+    rounded division, so its bits never move with the batch shape or
+    the backend.  x <= 0 maps to -1e4, which the PSNR clip floors out
+    exactly like the -inf a true log would give.  XLA CPU runs with
+    denormals-are-zero, so subnormal inputs take the same -1e4 branch --
+    a subnormal data range degrades to the clip caps, deterministically,
+    on every route.
     """
     x = x.astype(jnp.float32)
-    small = x < 2.0 ** -100
-    xs = jnp.where(small, x * jnp.float32(2.0 ** 64), x)
-    bits = jax.lax.bitcast_convert_type(xs, jnp.int32)
-    e = ((bits >> 23) & 0xFF) - 127
-    m = jax.lax.bitcast_convert_type(
-        (bits & 0x007FFFFF) | (127 << 23), jnp.float32)
-    t = (m - 1.0) / (m + 1.0)
-    s = t * t
-    p = jnp.float32(1.0 / 13.0)
-    for q in (1.0 / 11.0, 1.0 / 9.0, 1.0 / 7.0, 1.0 / 5.0, 1.0 / 3.0, 1.0):
-        p = p * s + jnp.float32(q)
-    log2m = (2.0 * _INV_LN2) * (t * p)
-    log2x = e.astype(jnp.float32) + log2m - jnp.where(small, 64.0, 0.0)
-    return jnp.where(x > 0.0, jnp.float32(_LOG10_2) * log2x,
+    return jnp.where(x > 0.0, jnp.float32(_LOG10_2) * f32.log2(x),
                      jnp.float32(-1e4))
 
 
@@ -154,7 +133,7 @@ def quality_from_stats(sse, n, vmin, vmax):
     one route and +inf on another.
     """
     rng = jnp.abs(vmax - vmin)[:, None]                      # (k, 1)
-    mse = sse / jnp.float32(n)
+    mse = f32.div(sse, jnp.float32(n))
     exact = sse == 0.0
     psnr = jnp.where(
         exact, jnp.float32(PSNR_CAP),
@@ -162,5 +141,5 @@ def quality_from_stats(sse, n, vmin, vmax):
                  -PSNR_CAP, PSNR_CAP))
     nrmse = jnp.where(
         exact, jnp.float32(0.0),
-        jnp.clip(jnp.sqrt(mse) / rng, 0.0, jnp.float32(NRMSE_CAP)))
+        jnp.clip(f32.div(jnp.sqrt(mse), rng), 0.0, jnp.float32(NRMSE_CAP)))
     return jnp.stack([psnr, nrmse], axis=-1)

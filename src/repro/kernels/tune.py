@@ -43,26 +43,14 @@ from typing import Any, Dict, Optional, Tuple
 
 SCHEMA_VERSION = 1
 
-# Per-backend hardware table: peak compute, memory bandwidth, and VMEM
-# per core.  The tpu-v5e entry is the 197 TF/s / 819 GB/s pair the
-# roofline report used as bare constants; ``benchmarks/roofline.py``
-# overrides the cpu entry's bandwidth with a measured STREAM triad so
-# the lane produces real fractions on CI hosts today.
+# Per-backend peaks for the roofline report.  tpu-v5e: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM per chip).
+# cpu: nominal dev-box numbers; ``benchmarks/roofline.py`` replaces its
+# bandwidth with a measured STREAM add.  A kind not listed here is an
+# error: a report against another device's peaks would be wrong.
 BACKEND_HW: Dict[str, Dict[str, float]] = {
-    "tpu-v5e": {"peak_flops": 197e12, "mem_bw": 819e9,
-                "vmem_bytes": 128 * 2**20},
-    "tpu-v4":  {"peak_flops": 275e12, "mem_bw": 1228e9,
-                "vmem_bytes": 128 * 2**20},
-    "tpu-v3":  {"peak_flops": 123e12, "mem_bw": 900e9,
-                "vmem_bytes": 16 * 2**20},
-    "tpu-v2":  {"peak_flops": 45e12, "mem_bw": 700e9,
-                "vmem_bytes": 16 * 2**20},
-    # nominal dev-box numbers; roofline measures the real bandwidth
-    "cpu":     {"peak_flops": 5e11, "mem_bw": 20e9,
-                "vmem_bytes": 16 * 2**20},
-    # unknown backends: conservative VMEM, v5e compute (report-only)
-    "default": {"peak_flops": 197e12, "mem_bw": 819e9,
-                "vmem_bytes": 16 * 2**20},
+    "tpu-v5e": {"peak_flops": 197e12, "mem_bw": 819e9},
+    "cpu":     {"peak_flops": 5e11, "mem_bw": 20e9},
 }
 
 _ALIASES = {"tpu-v5-lite": "tpu-v5e", "tpu-v5lite": "tpu-v5e",
@@ -82,23 +70,14 @@ def backend_kind() -> str:
 
 
 def hw_for(kind: Optional[str] = None) -> Dict[str, float]:
-    """Hardware entry for a backend kind (prefix-matched, with a
-    conservative ``default`` fallback)."""
-    kind = _ALIASES.get(kind or "", kind) or backend_kind()
-    if kind in BACKEND_HW:
-        return BACKEND_HW[kind]
-    for key in BACKEND_HW:
-        if key != "default" and kind.startswith(key):
-            return BACKEND_HW[key]
-    return BACKEND_HW["default"]
-
-
-def vmem_compare_budget(kind: Optional[str] = None) -> int:
-    """qent's per-sublane compare budget: half the backend's VMEM (the
-    other half holds the input tile, accumulator, and double-buffering).
-    Replaces the hardcoded 8 MB constant so TPU generations with 128 MB
-    VMEM stop over-shrinking tiles."""
-    return int(hw_for(kind)["vmem_bytes"]) // 2
+    """Peaks for a backend kind (default: the running backend).  Raises
+    ``ValueError`` for a kind the table does not list."""
+    kind = kind or backend_kind()
+    kind = _ALIASES.get(kind, kind)
+    if kind not in BACKEND_HW:
+        raise ValueError(f"no hardware peaks for backend kind {kind!r}; "
+                         f"known: {sorted(BACKEND_HW)}")
+    return BACKEND_HW[kind]
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import backend
+
 INTPREC = 26
 DEFAULT_BM = 128
 DEFAULT_BN = 128
@@ -89,5 +91,5 @@ def zfp_forward2d(x: jnp.ndarray, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN):
             jax.ShapeDtypeStruct((m, n), jnp.int32),
             jax.ShapeDtypeStruct((m // 4, n // 4), jnp.int32),
         ],
-        interpret=jax.default_backend() != "tpu",
+        interpret=backend.interpret(),
     )(x)

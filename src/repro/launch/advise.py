@@ -326,6 +326,8 @@ def main(argv=None) -> dict:
     ap.add_argument("--out", default="", help="write the JSON report here")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     mesh = None
     if args.mesh != "none":
         import jax

@@ -9,16 +9,24 @@ from __future__ import annotations
 import time
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """The one mesh constructor of this repo.
+
+    Every axis is ``Auto``: the sweep, train and serve paths leave
+    sharding propagation to XLA and slice ragged results freely, which
+    ``jax.make_mesh``'s default ``Explicit`` axes reject."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def _probe_coordinator_port(address: str, attempts: int = 10,
@@ -75,11 +83,7 @@ def dist_init(coordinator_address: str | None = None, *,
 
     Returns ``(process_index, process_count)``.
     """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation",
-                          cpu_collectives)
-    except Exception:
-        pass                 # older jax: CPU collectives not configurable
+    jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)
     if external_coordinator:
         if (coordinator_address is None or num_processes is None or
                 process_id is None):
@@ -87,8 +91,8 @@ def dist_init(coordinator_address: str | None = None, *,
                 "external_coordinator=True needs explicit "
                 "coordinator_address, num_processes and process_id")
         from jax._src import distributed as _dist
-        from jaxlib import xla_extension as _xe
-        client = _xe.get_distributed_runtime_client(
+        from jax._src.lib import _jax
+        client = _jax.get_distributed_runtime_client(
             coordinator_address, process_id,
             init_timeout=max(1, int(init_timeout_s)), use_compression=True)
         client.connect()
@@ -125,10 +129,10 @@ def serve_coordinator(address: str, num_processes: int,
     ``block=True`` serves until the process is killed; ``block=False``
     returns the service handle (caller keeps it alive).
     """
-    from jaxlib import xla_extension as _xe
+    from jax._src.lib import _jax
     _probe_coordinator_port(address)
     host, _, port = address.rpartition(":")
-    service = _xe.get_distributed_runtime_service(
+    service = _jax.get_distributed_runtime_service(
         f"[::]:{port}", int(num_processes))
     if not block:
         return service
@@ -174,7 +178,7 @@ def make_sweep_mesh(num_devices: int | None = None):
         raise ValueError(
             f"make_sweep_mesh({n}) exceeds the {len(devs)} visible "
             f"device(s) of this runtime{hint}")
-    return jax.make_mesh((n,), ("data",), devices=devs[:n])
+    return make_mesh((n,), ("data",), devices=devs[:n])
 
 
 # TPU v5e hardware model used by the roofline analysis (per chip).

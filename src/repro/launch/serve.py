@@ -46,6 +46,7 @@ def main():
 
     from repro.configs.base import get_arch, get_smoke
     from repro.dist import sharding as S
+    from repro.launch import mesh as M
     from repro.serve.engine import Engine, ServeConfig
     from repro.train import train_step as TS
 
@@ -80,7 +81,7 @@ def main():
         if args.mesh:
             shape = tuple(int(x) for x in args.mesh.split("x"))
             axes = ("data", "model") if len(shape) == 2 else ("pod", "data", "model")
-            with S.use_mesh(jax.make_mesh(shape, axes)):
+            with S.use_mesh(M.make_mesh(shape, axes)):
                 run()
         else:
             run()

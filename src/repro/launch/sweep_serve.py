@@ -142,6 +142,8 @@ def main():
     import jax.numpy as jnp
     from repro import compressors as C
     from repro.core import pipeline as PL, usecases as UC
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from repro.data import scientific
     from repro.serve.sweep_service import ServiceConfig, SweepService
 
@@ -150,8 +152,8 @@ def main():
         mesh = M.make_sweep_mesh()
     elif args.mesh and args.mesh != "auto":
         shape = tuple(int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh(shape, ("data",) if len(shape) == 1
-                             else ("data", "model"))
+        mesh = M.make_mesh(shape, ("data",) if len(shape) == 1
+                           else ("data", "model"))
 
     if args.coordinator is not None:
         from repro.dist import sweep as DS

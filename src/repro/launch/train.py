@@ -15,6 +15,7 @@ from repro.configs.base import get_arch, get_smoke
 from repro.ckpt.checkpoint import LossyPolicy
 from repro.data.tokens import make_data_iter
 from repro.dist import sharding as S
+from repro.launch import mesh as M
 from repro.train import loop as LOOP
 from repro.train import optimizer as OPT
 from repro.train import train_step as TS
@@ -58,7 +59,7 @@ def main():
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split("x"))
         axes = ("data", "model") if len(shape) == 2 else ("pod", "data", "model")
-        mesh = jax.make_mesh(shape, axes)
+        mesh = M.make_mesh(shape, axes)
         with S.use_mesh(mesh):
             run()
     else:

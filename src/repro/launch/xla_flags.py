@@ -38,14 +38,6 @@ PRESETS: Dict[str, FlagDict] = {
     "cpu": {
         "--xla_cpu_multi_thread_eigen": "true",
     },
-    # TPU pods: overlap collectives with compute and mark steps at the
-    # outer loop (the run.sh exemplar's step-marker choice: 0 = entry,
-    # 1 = outer while).
-    "tpu": {
-        "--xla_tpu_data_parallel_opt_different_sized_ops": "true",
-        "--xla_tpu_enable_data_parallel_all_reduce_opt": "true",
-        "--xla_step_marker_location": "1",
-    },
     # GPU: hide collective latency behind compute.
     "gpu": {
         "--xla_gpu_enable_latency_hiding_scheduler": "true",

@@ -1,15 +1,18 @@
-"""Shared quantization plumbing: code-range saturation + eps validation.
+"""Shared quantization plumbing: the codes, their saturation, eps validation.
 
 One home for the constants and checks that BOTH quantization routes (the
 jnp sort/histogram paths in ``repro.core.predictors`` and the Pallas
 kernel route in ``repro.kernels.qent``) must agree on exactly -- the
 sharded-equivalence gates depend on the routes staying bit-identical.
-Leaf module: imports nothing from core/kernels/dist.
+Imports nothing from core/kernels/dist.
 """
 from __future__ import annotations
 
 import numpy as np
 import jax
+import jax.numpy as jnp
+
+from repro import f32
 
 # floor(x/eps) is clamped to this f32-representable sub-range of int32
 # before any cast: the largest float32 not exceeding 2^31 - 1 is
@@ -17,6 +20,13 @@ import jax
 # code would corrupt run-length / histogram entropies).
 INT32_CODE_MIN = -2147483648.0
 INT32_CODE_MAX = 2147483520.0
+
+
+def codes(x, eps):
+    """``floor(x / eps)`` as int32, the quotient rounded as IEEE f32
+    division rounds it on every backend, clamped before the cast."""
+    q = jnp.floor(f32.div(x, eps))
+    return jnp.clip(q, INT32_CODE_MIN, INT32_CODE_MAX).astype(jnp.int32)
 
 
 def validate_eps_positive(epss) -> None:

@@ -132,7 +132,7 @@ def make_train_step(
         the cross-pod int8 all-gather ships whole tensors."""
         if param_specs is None:
             return tree
-        am = S.abstract_mesh_or(mesh)
+        am = jax.sharding.get_abstract_mesh()
         return jax.tree.map(
             lambda g, ns: jax.lax.with_sharding_constraint(
                 g, jax.sharding.NamedSharding(am, ns.spec)),
@@ -188,7 +188,7 @@ def make_train_step(
             else None
         pod = lambda t: jax.tree.map(lambda _: P("pod"), t)
         ef_spec = pod(ef_s) if ef_s is not None else None
-        out = S.shard_map(
+        out = jax.shard_map(
             per_pod, mesh=mesh,
             in_specs=(pod(params_s), pod(mu_s), pod(nu_s), P(),
                       ef_spec, P("pod")),

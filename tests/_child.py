@@ -2,7 +2,9 @@
 
 The main pytest process must keep the default single CPU device (jax
 locks the device count at first init), so every sharded scenario runs in
-a child interpreter with XLA_FLAGS set before importing jax.
+a child interpreter with XLA_FLAGS set before importing jax.  Children
+exist only to get virtual CPU devices, so they pin ``JAX_PLATFORMS=cpu``:
+on a machine with an accelerator they never compete for it.
 ``run_procs`` extends this to the multi-process fabric: N children join a
 ``jax.distributed`` coordinator on a free localhost port and run the SAME
 body SPMD (``PID``/``NPROCS`` are injected).
@@ -36,6 +38,7 @@ _PORT_RACE_RC = 47
 def _env(devices: int, extra: dict = None) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     if extra:
         env.update({k: str(v) for k, v in extra.items()})

@@ -20,7 +20,8 @@ def test_sharded_train_step_runs():
         from repro.data.tokens import make_data_iter
 
         cfg = get_smoke("granite-8b")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch import mesh as LM
+        mesh = LM.make_mesh((2, 4), ("data", "model"))
         with S.use_mesh(mesh):
             state = TS.init_state(cfg, jax.random.PRNGKey(0))
             shard = PRM.param_specs(M.param_table(cfg), mesh)
@@ -42,7 +43,8 @@ def test_logical_sharding_divisibility_fallback():
     out = run_child("""
         import jax
         from repro.dist import sharding as S
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch import mesh as LM
+        mesh = LM.make_mesh((2, 4), ("data", "model"))
         with S.use_mesh(mesh):
             # 20 heads on a 4-way model axis -> shards (20 % 4 == 0)
             s1 = S.spec_for((8, 20, 64), ("batch", "model", None))
@@ -63,7 +65,8 @@ def test_compressed_pod_allreduce_matches_mean():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.dist.collectives import compressed_pod_allreduce
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch import mesh as LM
+        mesh = LM.make_mesh((2, 2, 2), ("pod", "data", "model"))
         x = jax.random.normal(jax.random.PRNGKey(0), (2, 512)) * 0.01
 
         def podwise(xs):
@@ -86,7 +89,8 @@ def test_elastic_remesh():
     out = run_child("""
         import jax, jax.numpy as jnp
         from repro.dist import sharding as S, fault as F
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch import mesh as LM
+        mesh = LM.make_mesh((4, 2), ("data", "model"))
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
         axes = {"w": ("fsdp", "model")}
         with S.use_mesh(mesh):
@@ -115,7 +119,8 @@ def test_podsync_mode_compiles_and_runs():
         from repro.data.tokens import make_data_iter
 
         cfg = get_smoke("granite-3-2b")
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch import mesh as LM
+        mesh = LM.make_mesh((2, 2, 2), ("pod", "data", "model"))
         with S.use_mesh(mesh):
             state = TS.stack_for_podsync(
                 TS.init_state(cfg, jax.random.PRNGKey(0), compress=True), 2)

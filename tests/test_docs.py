@@ -175,14 +175,13 @@ def test_performance_doc_references_real_code():
     perf = _read("docs", "performance.md")
     for sym in ("repro.kernels.tune", "TuneConfig", "REPRO_TUNED_DIR",
                 "--xla-preset", "bench_tune", "BENCH_tune",
-                "measured_stream_bw", "BACKEND_HW",
-                "vmem_compare_budget", "invalidate_table_cache",
+                "measured_stream_bw", "BACKEND_HW", "invalidate_table_cache",
                 "apply_preset", "merge_flag_strings", "donate_argnums"):
         assert sym in perf, f"performance.md lost {sym}"
     # the knobs/presets the doc teaches must exist
     from repro.kernels import tune as KT
     from repro.launch import xla_flags as XF
-    for name in ("cpu", "tpu", "gpu", "none"):
+    for name in ("cpu", "gpu", "none"):
         assert name in XF.PRESETS
     assert hasattr(KT.TuneConfig(), "use_table")
     # the committed baseline the doc (and the default load path) relies on

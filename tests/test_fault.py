@@ -24,6 +24,7 @@ from _child import run_child, run_procs
 
 from repro.dist import fault as F
 from repro.dist import faultinject as FI
+from repro.launch import mesh as LM
 
 
 # ---------------------------------------------------------------------------
@@ -76,8 +77,7 @@ def test_faultinject_fire_counts_and_disarm():
 # ---------------------------------------------------------------------------
 
 def test_shrink_mesh_rejects_bad_axis_and_size():
-    import jax
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = LM.make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="not 'model'"):
         F.shrink_mesh(mesh, "model", 1)
     for bad in (0, 2):
@@ -87,10 +87,9 @@ def test_shrink_mesh_rejects_bad_axis_and_size():
 
 
 def test_surviving_submesh_rejects_nd_and_empty():
-    import jax
     with pytest.raises(ValueError, match="1-D"):
-        F.surviving_submesh(jax.make_mesh((1, 1), ("a", "b")), [0])
-    mesh = jax.make_mesh((1,), ("data",))
+        F.surviving_submesh(LM.make_mesh((1, 1), ("a", "b")), [0])
+    mesh = LM.make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="no devices left"):
         F.surviving_submesh(mesh, [99])
     sub = F.surviving_submesh(mesh, [0])
@@ -105,9 +104,10 @@ def test_remesh_state_preserves_values_across_shrink():
         import numpy as np, jax
         from repro.dist import fault as F
         from repro.dist import sharding as S
+        from repro.launch import mesh as LM
 
-        m4 = jax.make_mesh((4,), ("data",))
-        m22 = jax.make_mesh((2, 2), ("data", "model"))
+        m4 = LM.make_mesh((4,), ("data",))
+        m22 = LM.make_mesh((2, 2), ("data", "model"))
         tree = {"w": np.arange(32, dtype=np.float32).reshape(8, 4),
                 "b": np.arange(4, dtype=np.float32)}
         axes = {"w": ("batch", None), "b": (None,)}
@@ -146,7 +146,10 @@ def test_surviving_submesh_keeps_process_blocks_contiguous():
             assert tuple(sub.axis_names) == tuple(mesh.axis_names)
         print("SUBMESH OK", flush=True)
         sys.stdout.flush(); os._exit(0)
-    """, num_procs=2, devices=2, timeout=240)
+    """, num_procs=2, devices=2, timeout=240,
+        # process 0 finishing first must not close the coordination
+        # service under process 1
+        external_coordinator=True)
     for out in outs:
         assert "SUBMESH OK" in out
 

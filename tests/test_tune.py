@@ -169,12 +169,12 @@ def test_cpu_table_bitequal_to_defaults():
         assert np.array_equal(got, want), key
 
 
-def test_vmem_budget_from_backend_table():
-    assert KT.vmem_compare_budget("cpu") == 8 * 1024 * 1024
-    assert KT.vmem_compare_budget("tpu-v5e") == 64 * 1024 * 1024
-    assert KT.vmem_compare_budget("tpu-v5-lite") == 64 * 1024 * 1024
-    # unknown backends get the conservative default entry
-    assert KT.vmem_compare_budget("quantum") == 8 * 1024 * 1024
+def test_hw_for_rejects_unknown_kind():
+    assert KT.hw_for("tpu-v5e")["peak_flops"] == 197e12
+    assert KT.hw_for("tpu-v5-lite") is KT.hw_for("tpu-v5e")
+    assert KT.hw_for("cpu")["mem_bw"] > 0
+    with pytest.raises(ValueError, match="no hardware peaks"):
+        KT.hw_for("quantum")
 
 
 # ------------------------------------------------------------- donation
@@ -245,8 +245,8 @@ def test_apply_preset_user_wins():
     assert env["XLA_FLAGS"] == out
 
     env = {}
-    out = XF.apply_preset("tpu", extra={"--xla_extra": None}, env=env)
-    assert "--xla_step_marker_location=1" in out
+    out = XF.apply_preset("gpu", extra={"--xla_extra": None}, env=env)
+    assert "--xla_gpu_enable_latency_hiding_scheduler=true" in out
     assert "--xla_extra" in out
 
 
