@@ -21,6 +21,8 @@ from typing import Any, Dict, Tuple
 import numpy as np
 import jax.numpy as jnp
 
+from repro import obs
+
 
 class Compressor(abc.ABC):
     name: str = "base"
@@ -41,8 +43,13 @@ class Compressor(abc.ABC):
     # ------------------------------------------------------------------
     def cr(self, data: jnp.ndarray, eps: float) -> float:
         """Measured compression ratio (original fp32 bytes / compressed)."""
-        codes, aux = self.encode(data, eps)
-        size = self.size_bytes(codes, aux, eps)
+        with obs.span("repro.compress.run", compressor=self.name,
+                      eps=eps) as run:
+            with obs.span("repro.compress.encode"):
+                codes, aux = self.encode(data, eps)
+            with obs.span("repro.compress.size"):
+                size = self.size_bytes(codes, aux, eps)
+            run.attrs["bytes"] = size
         return float(data.size * 4) / max(size, 1)
 
     def roundtrip_error(self, data: jnp.ndarray, eps: float) -> float:

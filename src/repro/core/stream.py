@@ -42,6 +42,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import predictors as PRED
 from repro.data.source import DatasetSource, StreamingDigest, rows_per_chunk
 from repro.dist import sweep as DS
@@ -74,6 +75,17 @@ class StreamConfig:
 _DONE = object()
 
 
+def _read(source: DatasetSource, name: str, lo: int, hi: int,
+          digest: Optional[StreamingDigest]) -> np.ndarray:
+    """Stage rows [lo, hi): read + f32 convert + digest."""
+    with obs.span("repro.stream.read", rows=hi - lo) as sp:
+        arr = source.read_rows(name, lo, hi)
+        if digest is not None:
+            digest.update(arr)
+        sp.attrs["bytes"] = arr.nbytes
+    return arr
+
+
 def _reader(source: DatasetSource, name: str, schedule, q: "queue.Queue",
             digest: Optional[StreamingDigest]) -> None:
     """Reader-thread body: stage chunks (read + f32 convert + digest)
@@ -81,10 +93,7 @@ def _reader(source: DatasetSource, name: str, schedule, q: "queue.Queue",
     consumer re-raises them instead of hanging."""
     try:
         for lo, hi, rlo, rhi in schedule:
-            arr = source.read_rows(name, rlo, rhi)
-            if digest is not None:
-                digest.update(arr)
-            q.put((lo, hi, arr))
+            q.put((lo, hi, _read(source, name, rlo, rhi, digest)))
         q.put(_DONE)
     except BaseException as exc:             # noqa: BLE001 -- re-raised
         q.put(exc)
@@ -96,10 +105,7 @@ def _staged_chunks(source, name, schedule, prefetch: int,
     reader thread, or inline when ``prefetch == 0`` (synchronous)."""
     if prefetch <= 0:
         for lo, hi, rlo, rhi in schedule:
-            arr = source.read_rows(name, rlo, rhi)
-            if digest is not None:
-                digest.update(arr)
-            yield lo, hi, arr
+            yield lo, hi, _read(source, name, rlo, rhi, digest)
         return
     q: "queue.Queue" = queue.Queue(maxsize=prefetch)
     t = threading.Thread(target=_reader,
@@ -108,7 +114,8 @@ def _staged_chunks(source, name, schedule, prefetch: int,
     t.start()
     try:
         while True:
-            item = q.get()
+            with obs.span("repro.stream.wait"):
+                item = q.get()
             if item is _DONE:
                 break
             if isinstance(item, BaseException):
@@ -202,7 +209,9 @@ def stream_features(
 
     def drain_one() -> None:
         idx, out, rows = pending.popleft()
-        results[idx] = np.asarray(DS.gather_rows(out)[:rows], np.float32)
+        with obs.span("repro.stream.drain"):
+            results[idx] = np.asarray(DS.gather_rows(out)[:rows],
+                                      np.float32)
 
     chunks = _staged_chunks(source, name, schedule,
                             stream.prefetch, digest)
@@ -221,8 +230,10 @@ def stream_features(
         # row count): one compiled executable serves the whole stream,
         # ragged final chunk included, and the fresh staging copy's
         # upload is donated (zero-copy ingestion)
-        out = DS.sweep_padded(arr, epss_np, cfg, k_pad=chunk, mesh=mesh,
-                              donate=True, mode=mode)
+        with obs.span("repro.stream.launch", rows=rows,
+                      rows_launched=chunk):
+            out = DS.sweep_padded(arr, epss_np, cfg, k_pad=chunk, mesh=mesh,
+                                  donate=True, mode=mode)
         pending.append((idx, out, rows))
         # async dispatch: block only when the in-flight window is full
         # (prefetch=0 keeps the strictly synchronous baseline semantics)

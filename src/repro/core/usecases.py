@@ -29,6 +29,7 @@ from repro.core import predictors as P
 from repro.core.regression import predict_fast
 from repro.kernels.quality import PSNR_CAP
 from repro import compressors as C
+from repro import obs
 
 
 # Model outputs pass through np.log during cross-eb interpolation and
@@ -135,20 +136,25 @@ class EbGridModel:
         from repro.dist import sweep as DS
         # quality=True: the SAME pass also emits the fused PSNR/NRMSE
         # tensor, which becomes the training labels of the quality table
-        feats, qual = P.get_engine(cfg).sweep(
-            slices, np.asarray(ebs, np.float64), mesh=mesh, quality=True)
-        feats = np.asarray(feats)
+        with obs.span("repro.train.sweep", rows=len(slices), ebs=len(ebs)):
+            feats, qual = P.get_engine(cfg).sweep(
+                slices, np.asarray(ebs, np.float64), mesh=mesh,
+                quality=True)
+            feats = np.asarray(feats)
         # the compressor-run partition reuses the SAME mesh the sweep
         # sharded over (its processes), not an ad-hoc runtime-wide split
-        cr_table = DS.training_crs(comp, slices, ebs,
-                                   mesh=DS.active_sweep_mesh(mesh))
-        models = []
-        for i, eps in enumerate(ebs):
-            models.append(PL.CRPredictor.train_from_features(
-                jnp.asarray(feats[:, i, :]), jnp.asarray(cr_table[:, i]),
-                float(eps), model, cfg, ndim))
+        with obs.span("repro.train.compress", runs=len(slices) * len(ebs)):
+            cr_table = DS.training_crs(comp, slices, ebs,
+                                       mesh=DS.active_sweep_mesh(mesh))
+        with obs.span("repro.train.fit"):
+            models = []
+            for i, eps in enumerate(ebs):
+                models.append(PL.CRPredictor.train_from_features(
+                    jnp.asarray(feats[:, i, :]), jnp.asarray(cr_table[:, i]),
+                    float(eps), model, cfg, ndim))
+            quality = QualityTable.fit(feats, np.asarray(qual))
         return EbGridModel(np.asarray(ebs, np.float64), models, compressor,
-                           cfg, QualityTable.fit(feats, np.asarray(qual)))
+                           cfg, quality)
 
     @property
     def ndim(self) -> int:

@@ -51,6 +51,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from repro import compressors as C
+from repro import obs
 from repro.core import stream as ST
 from repro.core import usecases as UC
 from repro.core.predictors import PredictorConfig
@@ -175,69 +176,79 @@ def advise_variable(source: SRC.DatasetSource, name: str, *,
     and recommend only quality-feasible settings (see
     :func:`recommend`)."""
     meta = source.meta(name)
-    ndim = len(meta.shape) - 1
-    sample = source.read_rows(name, 0, min(int(train_rows), meta.rows))
-    rng = float(np.max(sample) - np.min(sample))
-    if rng <= 0:
-        return {"shape": list(meta.shape), "skipped": "constant sample"}
-    ebs = np.asarray([r * rng for r in grid_rels], np.float64)
+    with obs.span("repro.advise.variable", variable=name, rows=meta.rows,
+                  nbytes=meta.nbytes_f32):
+        ndim = len(meta.shape) - 1
+        sample = source.read_rows(name, 0, min(int(train_rows), meta.rows))
+        rng = float(np.max(sample) - np.min(sample))
+        if rng <= 0:
+            return {"shape": list(meta.shape), "skipped": "constant sample"}
+        ebs = np.asarray([r * rng for r in grid_rels], np.float64)
 
-    # the ONLY compressor executions of the whole run: the training
-    # sample (the paper's UC1/UC2 speedup structure -- everything else
-    # is predictor sweeps + model evaluations)
-    models = {comp: UC.EbGridModel.train(sample, comp, ebs, cfg=cfg,
-                                         ndim=ndim)
-              for comp in compressors}
+        # the ONLY compressor executions of the whole run: the training
+        # sample (the paper's UC1/UC2 speedup structure -- everything
+        # else is predictor sweeps + model evaluations)
+        models = {}
+        for comp in compressors:
+            with obs.span("repro.advise.train", compressor=comp):
+                models[comp] = UC.EbGridModel.train(sample, comp, ebs,
+                                                    cfg=cfg, ndim=ndim)
 
-    digest = SRC.StreamingDigest()
-    var_psnr = None
-    if service is not None:
-        # chunks ride the service's coalesced launches; futures overlap
-        # the next chunk's read exactly like the direct driver's
-        # in-flight window.  With a quality floor each chunk pairs its
-        # advise submission with a quality submission over the same
-        # rows/ebs, riding the same batch windows.
-        futs, qfuts = [], []
-        for _, chunk in source.chunks(name,
-                                      budget_bytes=stream.budget_bytes):
-            digest.update(chunk)
-            futs.append(service.submit_advise(models, chunk))
-            if psnr_floor is not None:
-                qfuts.append(service.submit_quality(chunk, ebs, cfg))
-        cr_rows = np.concatenate([f.result()["cr"] for f in futs], axis=0)
-        if qfuts:
-            qual = np.concatenate([f.result() for f in qfuts], axis=0)
-            var_psnr = qual[:, :, 0].min(axis=0)
-    else:
-        if psnr_floor is not None:
-            feats, qual = ST.stream_features(
-                source, name, ebs, cfg, stream=stream, mesh=mesh,
-                digest=digest, quality=True)
-            # worst row per eb: the variable meets the floor only when
-            # every row does
-            var_psnr = np.asarray(qual)[:, :, 0].min(axis=0)
-        else:
-            feats = ST.stream_features(source, name, ebs, cfg,
-                                       stream=stream, mesh=mesh,
-                                       digest=digest)
-        cr_rows = AdviseMethod.cr_table(models, feats)
+        digest = SRC.StreamingDigest()
+        var_psnr = None
+        with obs.span("repro.advise.stream"):
+            if service is not None:
+                # chunks ride the service's coalesced launches; futures
+                # overlap the next chunk's read exactly like the direct
+                # driver's in-flight window.  With a quality floor each
+                # chunk pairs its advise submission with a quality
+                # submission over the same rows/ebs, riding the same
+                # batch windows.
+                futs, qfuts = [], []
+                for _, chunk in source.chunks(
+                        name, budget_bytes=stream.budget_bytes):
+                    digest.update(chunk)
+                    futs.append(service.submit_advise(models, chunk))
+                    if psnr_floor is not None:
+                        qfuts.append(service.submit_quality(chunk, ebs, cfg))
+                cr_rows = np.concatenate([f.result()["cr"] for f in futs],
+                                         axis=0)
+                if qfuts:
+                    qual = np.concatenate([f.result() for f in qfuts],
+                                          axis=0)
+                    var_psnr = qual[:, :, 0].min(axis=0)
+            elif psnr_floor is not None:
+                feats, qual = ST.stream_features(
+                    source, name, ebs, cfg, stream=stream, mesh=mesh,
+                    digest=digest, quality=True)
+                # worst row per eb: the variable meets the floor only
+                # when every row does
+                var_psnr = np.asarray(qual)[:, :, 0].min(axis=0)
+            else:
+                feats = ST.stream_features(source, name, ebs, cfg,
+                                           stream=stream, mesh=mesh,
+                                           digest=digest)
 
-    var_cr = harmonic_cr(cr_rows)
-    names = tuple(models)
-    entry = {
-        "shape": list(meta.shape), "rows": meta.rows,
-        "digest": digest.digest(),
-        "eb_grid": [float(e) for e in ebs],
-        "value_range": rng,
-        "cr_by_compressor": {n: [float(c) for c in var_cr[i]]
-                             for i, n in enumerate(names)},
-        "targets": recommend(names, ebs, var_cr, targets,
-                             psnr_floor=psnr_floor, var_psnr=var_psnr),
-    }
-    if var_psnr is not None:
-        entry["psnr_floor"] = float(psnr_floor)
-        entry["psnr_by_eb"] = [float(p) for p in var_psnr]
-    return entry
+        with obs.span("repro.advise.recommend"):
+            if service is None:
+                cr_rows = AdviseMethod.cr_table(models, feats)
+            var_cr = harmonic_cr(cr_rows)
+            names = tuple(models)
+            entry = {
+                "shape": list(meta.shape), "rows": meta.rows,
+                "digest": digest.digest(),
+                "eb_grid": [float(e) for e in ebs],
+                "value_range": rng,
+                "cr_by_compressor": {n: [float(c) for c in var_cr[i]]
+                                     for i, n in enumerate(names)},
+                "targets": recommend(names, ebs, var_cr, targets,
+                                     psnr_floor=psnr_floor,
+                                     var_psnr=var_psnr),
+            }
+            if var_psnr is not None:
+                entry["psnr_floor"] = float(psnr_floor)
+                entry["psnr_by_eb"] = [float(p) for p in var_psnr]
+        return entry
 
 
 def advise_dataset(source: SRC.DatasetSource, *, compressors=None,
