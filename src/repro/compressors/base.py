@@ -1,8 +1,11 @@
 """Compressor API + registry.
 
 Every compressor exposes:
-  * ``encode(data, eps)   -> (codes, aux)``   jittable decorrelate+quantize
-  * ``decode(codes, aux, eps) -> recon``      jittable reconstruction
+  * ``encode(data, eps)   -> (codes, aux)``   decorrelate+quantize: one
+                                              compiled program per input
+                                              shape and dtype
+  * ``decode(codes, aux, eps) -> recon``      reconstruction, compiled
+                                              the same way
   * ``size_bytes(codes, aux, eps) -> int``    host-side real byte count
                                               (zstd-backed entropy stage)
   * ``cr(data, eps) -> float``                original_bytes / compressed
@@ -16,12 +19,43 @@ paper's regressions are therefore *real measured ratios*.
 from __future__ import annotations
 
 import abc
+import functools
 from typing import Any, Dict, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro import obs
+
+
+def compiled(compressor: str, static: Tuple[str, ...] = ()):
+    """``jax.jit`` for a compressor stage ``fn(*arrays, **static)``.
+
+    The error bound is one of the traced arrays, never static: a Python
+    or NumPy float is passed as a float32 scalar, so every ``eps`` of a
+    shape shares one program.  Static arguments (shapes, level counts)
+    are keyword-only.  The Python body records a
+    ``repro.compress.trace`` span (``compressor``; ``shape``, that of the
+    first input's first array), so the span marks each trace: once per
+    (stage, input shapes and dtypes) in a process.
+    """
+    def wrap(fn):
+        @functools.wraps(fn)
+        def traced(*args, **kw):
+            shape = tuple(jnp.shape(jax.tree.leaves(args[0])[0]))
+            with obs.span("repro.compress.trace", compressor=compressor,
+                          shape=shape):
+                return fn(*jax.tree.map(jnp.asarray, args), **kw)
+
+        program = jax.jit(traced, static_argnames=static)
+
+        @functools.wraps(fn)
+        def call(*args, **kw):
+            return program(*(np.float32(a) if isinstance(a, (float, np.floating))
+                             else a for a in args), **kw)
+        return call
+    return wrap
 
 
 class Compressor(abc.ABC):

@@ -17,20 +17,16 @@ import jax
 import jax.numpy as jnp
 
 from repro.compressors import base, lossless
-from repro.compressors.sz import quantize_bounded
+from repro.compressors.sz import interleave, dequantize, quantize_bounded
 
 
 def _interp_even_to_full(coarse: jnp.ndarray, full_shape, axis: int) -> jnp.ndarray:
     """Linear interpolation from even-index samples to the full grid along
     ``axis`` (odd points = average of neighbours, edge clamped)."""
     c = jnp.moveaxis(coarse, axis, 0)
-    n_full = full_shape[axis]
     nxt = jnp.concatenate([c[1:], c[-1:]], axis=0)
     odd = 0.5 * (c + nxt)
-    out_shape = (n_full,) + c.shape[1:]
-    out = jnp.zeros(out_shape, c.dtype)
-    out = out.at[0::2].set(c[: (n_full + 1) // 2])
-    out = out.at[1::2].set(odd[: n_full // 2])
+    out = interleave(c, odd[: full_shape[axis] // 2], 0)
     return jnp.moveaxis(out, 0, axis)
 
 
@@ -47,47 +43,47 @@ def _restrict(data: jnp.ndarray) -> jnp.ndarray:
     return data[sl]
 
 
+@base.compiled("mgard", static=("levels",))
+def _mgard_encode(data, eps, *, levels):
+    """Root codes and the level codes, coarsest level first."""
+    fines = [data.astype(jnp.float32)]
+    for _ in range(levels):
+        if min(fines[-1].shape) < 4:
+            break
+        fines.append(_restrict(fines[-1]))
+    # Quantize from the coarsest level outward so predictions use
+    # reconstructed values (exact error-bound preservation).
+    root = quantize_bounded(fines.pop(), eps)
+    recon = dequantize(root, eps)
+    level_codes = []
+    for fine in reversed(fines):
+        pred = _predict_fine(recon, fine.shape)
+        c = quantize_bounded(fine - pred, eps)
+        level_codes.append(c)
+        recon = pred + dequantize(c, eps)
+    return root, level_codes
+
+
+@base.compiled("mgard")
+def _mgard_decode(root, level_codes, eps):
+    recon = dequantize(root, eps)
+    for c in level_codes:
+        recon = _predict_fine(recon, c.shape) + dequantize(c, eps)
+    return recon
+
+
 class MGARD(base.Compressor):
     name = "mgard"
     levels = 4
 
     def encode(self, data, eps):
-        data = data.astype(jnp.float32)
-        shapes, codes = [], []
-        cur = data
-        for _ in range(self.levels):
-            if min(cur.shape) < 4:
-                break
-            coarse = _restrict(cur)
-            shapes.append(cur.shape)
-            codes.append(None)  # placeholder, filled in reverse pass
-            cur = coarse
-        # Quantize from the coarsest level outward so predictions use
-        # reconstructed values (exact error-bound preservation).
-        root_codes = quantize_bounded(cur, eps)
-        recon = root_codes.astype(jnp.float32) * (2.0 * eps)
-        level_codes = []
-        # We must re-derive each level's fine data: walk shapes in reverse.
-        fines = []
-        cur2 = data
-        for shape in shapes:
-            fines.append(cur2)
-            cur2 = _restrict(cur2)
-        for fine, shape in zip(reversed(fines), reversed(shapes)):
-            pred = _predict_fine(recon, shape)
-            resid = fine - pred
-            c = quantize_bounded(resid, eps)
-            level_codes.append(c)
-            recon = pred + c.astype(jnp.float32) * (2.0 * eps)
-        return (root_codes, level_codes), {"shape": data.shape, "shapes": shapes}
+        root, level_codes = _mgard_encode(data, eps, levels=self.levels)
+        shapes = [tuple(c.shape) for c in reversed(level_codes)]
+        return (root, level_codes), {"shape": data.shape, "shapes": shapes}
 
     def decode(self, codes, aux, eps):
-        root_codes, level_codes = codes
-        recon = root_codes.astype(jnp.float32) * (2.0 * eps)
-        for c, shape in zip(level_codes, reversed(aux["shapes"])):
-            pred = _predict_fine(recon, shape)
-            recon = pred + c.astype(jnp.float32) * (2.0 * eps)
-        return recon
+        root, level_codes = codes
+        return _mgard_decode(root, level_codes, eps)
 
     def size_bytes(self, codes, aux, eps):
         root_codes, level_codes = codes

@@ -26,34 +26,40 @@ def _floats(b: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.bitcast_convert_type(b.astype(jnp.uint32), jnp.float32)
 
 
+@base.compiled("bitgrooming")
+def _groom(data, eps):
+    """Alternately shaved and set low mantissa bits, and the count of
+    them, ``k``: masking k bits of a value with exponent e errs by
+    < 2^(e-23+k), bounded by the field's largest exponent."""
+    data = data.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(data))
+    emax = jnp.floor(jnp.log2(jnp.maximum(amax, 1e-38)))
+    k = jnp.clip(23 + jnp.floor(jnp.log2(eps)) - emax, 0, 23).astype(jnp.uint32)
+    b = _bits(data)
+    mask = (~jnp.uint32(0)) << k
+    flat_idx = jnp.arange(data.size).reshape(data.shape)
+    groomed = jnp.where(flat_idx % 2 == 0, b & mask, b | (~mask))
+    # keep exact zeros exact (grooming convention)
+    groomed = jnp.where(b == 0, b, groomed)
+    return _floats(groomed), k
+
+
+@base.compiled("digitrounding")
+def _round_digits(data, eps):
+    step = jnp.exp2(jnp.floor(jnp.log2(eps)))  # largest pow2 <= eps
+    return (jnp.round(data.astype(jnp.float32) / step) * step).astype(jnp.float32)
+
+
 class BitGrooming(base.Compressor):
     """Zender 2016: alternately shave (to 0) and set (to 1) insignificant
     mantissa bits; the number of kept bits is global, derived from eps and
-    the field's max exponent (OptZConfig absolute-bound mapping)."""
+    the field's max exponent (OptZConfig absolute-bound mapping).  The
+    groomed values are their own reconstruction."""
     name = "bitgrooming"
 
-    def _mask_bits(self, data: jnp.ndarray, eps: float) -> jnp.ndarray:
-        amax = jnp.max(jnp.abs(data))
-        emax = jnp.floor(jnp.log2(jnp.maximum(amax, 1e-38)))
-        # masking k low mantissa bits of a value with exponent e gives
-        # error < 2^(e-23+k); bound by worst-case exponent emax.
-        k = jnp.clip(
-            23 + jnp.floor(jnp.log2(eps)) - emax, 0, 23
-        ).astype(jnp.uint32)
-        return k
-
     def encode(self, data, eps):
-        data = data.astype(jnp.float32)
-        k = self._mask_bits(data, eps)
-        b = _bits(data)
-        mask = (~jnp.uint32(0)) << k
-        flat_idx = jnp.arange(data.size).reshape(data.shape)
-        shave = (b & mask)
-        setb = (b | (~mask))
-        groomed = jnp.where(flat_idx % 2 == 0, shave, setb)
-        # keep exact zeros exact (grooming convention)
-        groomed = jnp.where(b == 0, b, groomed)
-        return _floats(groomed), {"shape": data.shape, "keepbits": k}
+        groomed, k = _groom(data, eps)
+        return groomed, {"shape": data.shape, "keepbits": k}
 
     def decode(self, codes, aux, eps):
         return codes
@@ -68,10 +74,7 @@ class DigitRounding(base.Compressor):
     name = "digitrounding"
 
     def encode(self, data, eps):
-        data = data.astype(jnp.float32)
-        step = jnp.exp2(jnp.floor(jnp.log2(eps)))  # largest pow2 <= eps
-        rounded = jnp.round(data / step) * step
-        return rounded.astype(jnp.float32), {"shape": data.shape}
+        return _round_digits(data, eps), {"shape": data.shape}
 
     def decode(self, codes, aux, eps):
         return codes

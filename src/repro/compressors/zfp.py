@@ -141,6 +141,7 @@ def zfp_truncate(q: jnp.ndarray, e: jnp.ndarray, eps: float) -> jnp.ndarray:
     )
 
 
+@base.compiled("zfp")
 def zfp_size_bits(q: jnp.ndarray, e: jnp.ndarray, eps: float) -> jnp.ndarray:
     """Embedded-coding size model: per coefficient, bits above the cutoff
     plane + sign, plus per-block header (exponent + group tests)."""
@@ -155,16 +156,30 @@ def zfp_size_bits(q: jnp.ndarray, e: jnp.ndarray, eps: float) -> jnp.ndarray:
     return jnp.sum(per_block + header)
 
 
+@base.compiled("zfp")
+def _zfp_encode(data, eps):
+    q, e, _ = zfp_transform(data)
+    return zfp_truncate(q, e, eps), e
+
+
+def _padded4(shape) -> Tuple[int, ...]:
+    return tuple(s + (-s) % 4 for s in shape)
+
+
+@base.compiled("zfp", static=("shape",))
+def _zfp_decode(codes, e, *, shape):
+    return zfp_untransform(codes, e, _padded4(shape), shape)
+
+
 class ZFP(base.Compressor):
     name = "zfp"
 
     def encode(self, data, eps):
-        q, e, padded_shape = zfp_transform(data)
-        qt = zfp_truncate(q, e, eps)
-        return qt, {"e": e, "padded": padded_shape, "shape": data.shape}
+        qt, e = _zfp_encode(data, eps)
+        return qt, {"e": e, "padded": _padded4(data.shape), "shape": data.shape}
 
     def decode(self, codes, aux, eps):
-        return zfp_untransform(codes, aux["e"], aux["padded"], aux["shape"])
+        return _zfp_decode(codes, aux["e"], shape=tuple(aux["shape"]))
 
     def size_bytes(self, codes, aux, eps):
         bits = float(zfp_size_bits(codes, aux["e"], eps))

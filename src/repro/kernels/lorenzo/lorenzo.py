@@ -19,19 +19,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.compressors.sz import quantize_bounded as _quantize
 from repro.kernels import backend
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
-
-
-def _quantize(x, eps):
-    q = jnp.round(x / (2.0 * eps)).astype(jnp.int32)
-    for _ in range(2):
-        recon = jax.lax.optimization_barrier(q.astype(jnp.float32) * (2.0 * eps))
-        err = x - recon
-        q = q + (err > eps).astype(jnp.int32) - (err < -eps).astype(jnp.int32)
-    return q
 
 
 def _lorenzo_kernel(eps_ref, a_ref, b_ref, c_ref, d_ref, o_ref):

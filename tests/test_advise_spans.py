@@ -144,3 +144,20 @@ def test_the_report_does_not_depend_on_the_spans(dataset, monkeypatch):
     before = obs.records()[-1].index
     assert _advise(dataset) == traced
     assert obs.records()[-1].index == before
+
+
+def test_a_second_variable_of_a_shape_compiles_nothing(tmp_path):
+    """The compressors' programs are traced while the first variable of
+    a new row shape trains, and never for the second."""
+    edge = 23               # a row shape no other test of this file uses
+    gen = SRC.GeneratorSource([
+        SRC.FieldVariable("scale-u", ROWS, (edge,), seed=3),
+        SRC.FieldVariable("hurricane-u", ROWS, (edge,), seed=4)])
+    source = SRC.open_dataset(SRC.write_dataset(
+        str(tmp_path / "ds"), gen, fmt="memmap", dtype="float32"))
+    _, spans = _traced(source)
+    first, second = _names(spans, "repro.advise.variable")
+    traces = _names(_under(spans, first), "repro.compress.trace")
+    assert {s.attrs["compressor"] for s in traces} == set(COMPRESSORS)
+    assert all(s.attrs["shape"] == (edge, edge) for s in traces)
+    assert not _names(_under(spans, second), "repro.compress.trace")

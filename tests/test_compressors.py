@@ -7,28 +7,77 @@ import pytest
 from _hyp import given, settings, st
 
 from repro import compressors as C
+from repro import obs
 from repro.compressors.base import error_bound_slack
 from repro.compressors.sz import SZ2, quantize_bounded
 from repro.data import gaussian, scientific
 
 
 FIELDS = ["miranda-vx", "scale-u", "hurricane-u", "cesm-cloud"]
+# an edge that is a multiple of neither ZFP's 4 nor SZ's 6
+EDGE_50 = "hurricane-u-50x50"
 
 
 @pytest.fixture(scope="module")
 def slices():
-    return {f: scientific.field_slices(f, count=1, n=96)[0] for f in FIELDS}
+    out = {f: scientific.field_slices(f, count=1, n=96)[0] for f in FIELDS}
+    out[EDGE_50] = scientific.field_slices("hurricane-u", count=1, n=50)[0]
+    return out
 
 
 @pytest.mark.parametrize("name", C.STUDY_2D)
-@pytest.mark.parametrize("field", FIELDS)
+@pytest.mark.parametrize("field", FIELDS + [EDGE_50])
 def test_error_bound_held(name, field, slices):
+    """The compiled decode of the compiled encode holds the bound."""
     x = slices[field]
     rng = float(jnp.max(x) - jnp.min(x))
-    for eps_rel in (1e-2, 1e-4):
+    for eps_rel in (1e-2, 1e-3, 1e-4):
         eps = eps_rel * rng
         err = C.get(name).roundtrip_error(x, eps)
         assert err <= eps + error_bound_slack(x), (name, field, eps_rel, err / eps)
+
+
+def _leaves(tree):
+    """(structure, leaves as arrays) of a (codes, aux) or a recon."""
+    leaves, treedef = jax.tree.flatten(tree)
+    return treedef, [np.asarray(leaf) for leaf in leaves]
+
+
+@pytest.mark.parametrize("name", C.STUDY_2D)
+def test_compiled_equals_op_by_op(name, slices):
+    """Each compressor's compiled encode and decode give the bits of the
+    same functions run op by op: codes, aux (tree, shapes, dtypes) and
+    the reconstruction."""
+    x = slices[EDGE_50]
+    comp = C.get(name)
+    rng = float(jnp.max(x) - jnp.min(x))
+    for eps_rel in (1e-2, 1e-4):
+        eps = eps_rel * rng
+        codes, aux = comp.encode(x, eps)
+        got = _leaves((codes, aux, comp.decode(codes, aux, eps)))
+        with jax.disable_jit():
+            codes0, aux0 = comp.encode(x, eps)
+            want = _leaves((codes0, aux0, comp.decode(codes0, aux0, eps)))
+        assert got[0] == want[0], (name, eps_rel)
+        for a, b in zip(got[1], want[1]):
+            assert a.dtype == b.dtype and np.array_equal(a, b), (name, eps_rel)
+
+
+@pytest.mark.parametrize("name", C.STUDY_2D)
+def test_one_trace_per_shape_whatever_eps(name):
+    """Five error bounds, as Python, float64 and float32 scalars, on one
+    shape: the encoder is traced (and compiled) once."""
+    x = scientific.field_slices("scale-u", count=1, n=64)[0][:29, :35]
+    rng = float(jnp.max(x) - jnp.min(x))
+    with obs.span("test.mark") as mark:
+        pass
+    for eps in (1e-4 * rng, np.float64(3e-4 * rng), np.float32(1e-3 * rng),
+                3e-3 * rng, 1e-2 * rng):
+        C.get(name).encode(x, eps)
+    traces = [r for r in obs.records() if r.index > mark.index
+              and r.name == "repro.compress.trace"]
+    assert [(r.attrs["compressor"], r.attrs["shape"]) for r in traces] == [
+        (name, (29, 35))]
 
 
 @pytest.mark.parametrize("name", C.STUDY_2D)
